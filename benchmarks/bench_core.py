@@ -130,8 +130,8 @@ def bench_masked_round(rows, *, n_params=10_000_000,
               "seed_baseline": {}, "notes": {
                   "mask_s": "one client masking one packed buffer",
                   "aggregate_s": "server (N,T)->(T,) reduction, "
-                                 "kernel ops path (jnp oracle fallback on "
-                                 "CPU interpret mode)",
+                                 "kernel ops path (jnp oracle off the "
+                                 "TPU)",
                   "stream_aggregate_s": "same reduction through the "
                                         "streaming sink (fold-on-arrival, "
                                         "O(T) accumulator), full fold "

@@ -6,7 +6,7 @@ Sections:
   aggregation.*  — Model Aggregator strategies (paper §V)
   secure_agg.*   — §VII privacy path (masking + fused kernel)
   communicator.* — §V Communicator (pack/encrypt/decrypt)
-  kernels.*      — Pallas kernels (interpret mode on CPU)
+  kernels.*      — Pallas kernels (interpret mode on CPU, compiled on TPU)
   fl_round.*     — end-to-end round: control-plane overhead
   roofline.*     — dry-run roofline summaries (if artifacts exist)
 """

@@ -82,7 +82,7 @@ def aggregate_packed(name: str, buffers,
     """Aggregate (N, T) packed fp32 client buffers in one reduction.
 
     ``buffers`` is an (N, T) array or a list of (T,) buffers. FedAvg goes
-    through the fused Pallas combine (jnp oracle in interpret mode) with
+    through the fused Pallas combine (jnp oracle off the TPU) with
     weights *normalized* to a weighted mean (masked rounds instead use
     ``secure_agg.aggregate_masked_packed``, whose weights stay raw so
     pre-scaled protocols can sum); the robust strategies sort/median on

@@ -43,7 +43,7 @@ benchmarks/bench_compression.py).
 The server side reduces a cohort of posted wire messages in one pass
 (``reduce_compressed``): int8 cohorts go through the fused Pallas
 dequantize-scale-accumulate kernel (``kernels/compressed_agg``, jnp
-oracle in interpret mode); top-k cohorts scatter-add their weighted
+oracle off the TPU); top-k cohorts scatter-add their weighted
 (index, value) pairs into the dense (T,) result — never materializing
 per-client dense buffers.
 
@@ -240,7 +240,7 @@ def reduce_compressed(msgs: Sequence[Dict], weights: Sequence[float], *,
     per-client buffers: int8 cohorts fold through the fused Pallas
     dequantize-scale-accumulate kernel in bounded batches (a streaming
     ``QuantSink``, ``core/streaming.py`` — O(T) accumulator memory, mesh-
-    sharded over T when a mesh is up; jnp oracle in interpret mode);
+    sharded over T when a mesh is up; jnp oracle off the TPU);
     top-k cohorts accumulate weighted (index, value) pairs into the
     output via fancy indexing (every message's indices are unique by
     construction, so no ``np.add.at``). Weights are used as given — the
@@ -265,7 +265,7 @@ def reduce_masked(msgs: Sequence[Dict], *,
     Streams the cohort's residue arrays into a (T',) uint32 accumulator
     (``core/streaming.py`` ``ModularSink``) in bounded batches — the
     (N, T') stack never materializes — then one fused masked-dequantize
-    decode at the end (jnp oracle in interpret mode). uint32 wrap-around
+    decode at the end (jnp oracle off the TPU). uint32 wrap-around
     preserves residues mod M = 2**mbits, so the fold is associative and
     the result is BIT-EXACT regardless of arrival order: the pairwise
     masks cancel exactly, the residue is centered and scaled by the

@@ -163,8 +163,8 @@ def aggregate_masked_packed(buffers, weights: Optional[Sequence[float]]
     (handled by the caller). ``weights`` therefore defaults to the uniform
     mean and is exposed only for pre-scaled protocols — unlike
     ``aggregation.aggregate_packed`` it is NOT normalized, so pre-scaled
-    sums stay sums. Routed through the fused Pallas combine (jnp oracle in
-    interpret mode).
+    sums stay sums. Routed through the fused Pallas combine (jnp oracle off
+    the TPU).
 
     ``corrections`` (dropout repair): an (N, T) matrix of per-survivor
     correction buffers (``repair_correction``), subtracted row-wise before

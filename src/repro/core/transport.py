@@ -535,6 +535,9 @@ class SocketTransportServer:
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        # importing repro.core imports JAX; the child must never take the
+        # accelerator the parent holds (one process per chip)
+        env["JAX_PLATFORMS"] = "cpu"
         self._proc = subprocess.Popen(
             [sys.executable, "-c",
              "from repro.core.transport import _serve_main; "

@@ -6,7 +6,7 @@ from functools import partial
 
 import jax
 
-from repro import kernels
+from repro.kernels import on_tpu
 from repro.kernels.compressed_agg import kernel as _k
 from repro.kernels.compressed_agg import ref as _ref
 
@@ -20,16 +20,17 @@ def dequant_reduce(q, scales, weights, *, interpret: bool = None):
 
     ``sum_i weights_i * dequant(q_i, scales_i)`` — the server-side
     reduction of the compressed data plane (DESIGN.md §Compressed data
-    plane). On TPU (``kernels.INTERPRET = False``) this is the fused
-    Pallas combine; in interpret mode it falls back to the jnp oracle in
-    ``ref.py``, which is also the definition the kernel is parity-tested
-    against (tests/test_compression.py).
+    plane). On a TPU this is always the fused Pallas combine. On other
+    backends ``interpret=None`` runs the jnp oracle in ``ref.py``, which
+    is also the definition the kernel is parity-tested against
+    (tests/test_compression.py); ``interpret=True`` (tests) runs the
+    kernel body through the Pallas interpreter.
     """
     if interpret is None:
-        interpret = kernels.INTERPRET
-    if interpret:
-        return _ref.dequant_reduce_ref(q, scales, weights)
-    return _k.dequant_reduce_flat(q, scales, weights, interpret=False)
+        if not on_tpu():
+            return _ref.dequant_reduce_ref(q, scales, weights)
+        interpret = False
+    return _k.dequant_reduce_flat(q, scales, weights, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("modulus_bits", "interpret"))
@@ -44,15 +45,15 @@ def masked_dequant_reduce(z, scales, *, modulus_bits: int, corr=None,
     masks cancel bit-exactly before the centered decode and the
     common-grid dequant. No per-client weights — weighting is
     pre-applied client-side, exactly like the packed fp32 secure plane.
-    On TPU this is the fused Pallas combine; interpret mode falls back
-    to the jnp oracle it is parity-tested against
+    On a TPU this is always the fused Pallas combine; off it,
+    ``interpret=None`` runs the jnp oracle it is parity-tested against
     (tests/test_composable_privacy.py).
     """
     if interpret is None:
-        interpret = kernels.INTERPRET
-    if interpret:
-        return _ref.masked_dequant_reduce_ref(z, scales, modulus_bits,
-                                              corr=corr)
+        if not on_tpu():
+            return _ref.masked_dequant_reduce_ref(z, scales, modulus_bits,
+                                                  corr=corr)
+        interpret = False
     return _k.masked_dequant_reduce_flat(z, scales,
                                          modulus_bits=modulus_bits,
-                                         corr=corr, interpret=False)
+                                         corr=corr, interpret=interpret)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import EXACT_F32
 from repro.kernels.compressed_agg.kernel import CHUNK
 
 
@@ -27,7 +28,8 @@ def dequant_reduce_ref(q, scales, weights):
     c = t // CHUNK
     deq = (q.astype(jnp.float32).reshape(n, c, CHUNK)
            * scales.astype(jnp.float32)[:, :, None]).reshape(n, t)
-    return jnp.tensordot(weights.astype(jnp.float32), deq, axes=(0, 0))
+    return jnp.tensordot(weights.astype(jnp.float32), deq, axes=(0, 0),
+                         precision=EXACT_F32)
 
 
 def masked_dequant_reduce_ref(z, scales, modulus_bits: int, corr=None):

@@ -37,8 +37,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
 
     def body(ki, carry):
         acc, m_prev, l_prev = carry
-        k = pl.load(k_ref, (pl.ds(ki * bk, bk), slice(None)))
-        v = pl.load(v_ref, (pl.ds(ki * bk, bk), slice(None)))
+        k = k_ref[pl.ds(ki * bk, bk), :]
+        v = v_ref[pl.ds(ki * bk, bk), :]
         s = jnp.dot(q, k.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32)      # (BQ, BK)
         if softcap > 0.0:

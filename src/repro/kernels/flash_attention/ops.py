@@ -5,7 +5,7 @@ from functools import partial
 
 import jax
 
-from repro import kernels
+from repro.kernels import on_tpu
 from repro.kernels.flash_attention import kernel as _k
 
 
@@ -18,7 +18,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = kernels.INTERPRET
+        interpret = not on_tpu()
     qt = q.swapaxes(1, 2)
     kt = k.swapaxes(1, 2)
     vt = v.swapaxes(1, 2)

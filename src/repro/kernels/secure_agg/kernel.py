@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import EXACT_F32
+
 DEFAULT_BT = 4096
 
 
@@ -23,7 +25,8 @@ def _combine_kernel(q_ref, ws_ref, o_ref):
     """q_ref: (N, BT) int8; ws_ref: (1, N) f32 (= weights*scales);
     o_ref: (1, BT) f32."""
     q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.dot(ws_ref[...], q, preferred_element_type=jnp.float32)
+    o_ref[...] = jnp.dot(ws_ref[...], q, precision=EXACT_F32,
+                         preferred_element_type=jnp.float32)
 
 
 def _combine_call(q, ws, *, bt: int, interpret: bool, corr=None):
@@ -88,7 +91,8 @@ def _combine_corrected_kernel(x_ref, c_ref, ws_ref, o_ref):
     separate "repaired updates" matrix.
     """
     d = x_ref[...] - c_ref[...]
-    o_ref[...] = jnp.dot(ws_ref[...], d, preferred_element_type=jnp.float32)
+    o_ref[...] = jnp.dot(ws_ref[...], d, precision=EXACT_F32,
+                         preferred_element_type=jnp.float32)
 
 
 def masked_sum_corrected_flat(x, corr, weights, *, bt: int = DEFAULT_BT,

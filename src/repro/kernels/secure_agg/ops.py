@@ -8,7 +8,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import kernels
+from repro.kernels import on_tpu
 from repro.kernels.secure_agg import kernel as _k
 from repro.kernels.secure_agg import ref as _ref
 
@@ -17,7 +17,7 @@ from repro.kernels.secure_agg import ref as _ref
 def secure_agg_combine(q, scales, weights, *, interpret: bool = None):
     """q: (N, T) int8; scales, weights: (N,) f32 -> (T,) f32."""
     if interpret is None:
-        interpret = kernels.INTERPRET
+        interpret = not on_tpu()
     return _k.secure_agg_combine_flat(q, scales, weights,
                                       interpret=interpret)
 
@@ -26,17 +26,18 @@ def secure_agg_combine(q, scales, weights, *, interpret: bool = None):
 def masked_sum(x, weights, *, interpret: bool = None):
     """Weighted sum of packed fp32 masked updates: (N, T), (N,) -> (T,).
 
-    On TPU (``kernels.INTERPRET = False``) this is the fused Pallas MXU
-    combine; in interpret mode it falls back to the jnp oracle in
-    ``ref.py`` — interpreting the kernel block-by-block at 10M+ parameter
-    sizes is prohibitively slow on CPU, and the oracle is the definition
-    the kernel is tested against anyway (tests/test_kernels.py).
+    On a TPU this is always the fused Pallas MXU combine. On other
+    backends ``interpret=None`` runs the jnp oracle in ``ref.py`` —
+    interpreting the kernel block-by-block at 10M+ parameter sizes is
+    prohibitively slow on CPU, and the oracle is the definition the
+    kernel is tested against anyway. ``interpret=True`` (tests) runs the
+    kernel body through the Pallas interpreter.
     """
     if interpret is None:
-        interpret = kernels.INTERPRET
-    if interpret:
-        return _ref.masked_sum_ref(x, weights)
-    return _k.masked_sum_flat(x, weights, interpret=False)
+        if not on_tpu():
+            return _ref.masked_sum_ref(x, weights)
+        interpret = False
+    return _k.masked_sum_flat(x, weights, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -46,15 +47,16 @@ def masked_sum_corrected(x, corr, weights, *, interpret: bool = None):
     ``sum_i weights_i * (x_i - corr_i)`` — survivors' masked updates minus
     their re-derived corrections against the dropped peers, fused into one
     Pallas tile pass on TPU (the correction subtract rides the VPU inside
-    the combine tile, no repaired (N, T) intermediate in HBM). Interpret
-    mode falls back to the jnp oracle for the same reason ``masked_sum``
-    does.
+    the combine tile, no repaired (N, T) intermediate in HBM). Off the
+    TPU, ``interpret=None`` runs the jnp oracle for the same reason
+    ``masked_sum`` does.
     """
     if interpret is None:
-        interpret = kernels.INTERPRET
-    if interpret:
-        return _ref.masked_sum_corrected_ref(x, corr, weights)
-    return _k.masked_sum_corrected_flat(x, corr, weights, interpret=False)
+        if not on_tpu():
+            return _ref.masked_sum_corrected_ref(x, corr, weights)
+        interpret = False
+    return _k.masked_sum_corrected_flat(x, corr, weights,
+                                        interpret=interpret)
 
 
 def quantize_update(update_flat: jnp.ndarray):

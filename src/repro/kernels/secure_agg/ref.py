@@ -10,10 +10,13 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels import EXACT_F32
+
 
 def secure_agg_ref(q, scales, weights):
     deq = q.astype(jnp.float32) * scales[:, None]
-    return jnp.tensordot(weights.astype(jnp.float32), deq, axes=(0, 0))
+    return jnp.tensordot(weights.astype(jnp.float32), deq, axes=(0, 0),
+                         precision=EXACT_F32)
 
 
 def masked_sum_ref(x, weights):
@@ -29,7 +32,8 @@ def masked_sum_ref(x, weights):
     sizes is orders of magnitude slower than this single XLA matvec.
     """
     return jnp.tensordot(weights.astype(jnp.float32),
-                         x.astype(jnp.float32), axes=(0, 0))
+                         x.astype(jnp.float32), axes=(0, 0),
+                         precision=EXACT_F32)
 
 
 def masked_sum_corrected_ref(x, corr, weights):
@@ -47,4 +51,5 @@ def masked_sum_corrected_ref(x, corr, weights):
     """
     return jnp.tensordot(weights.astype(jnp.float32),
                          x.astype(jnp.float32) - corr.astype(jnp.float32),
-                         axes=(0, 0))
+                         axes=(0, 0),
+                         precision=EXACT_F32)

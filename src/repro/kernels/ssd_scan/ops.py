@@ -5,14 +5,14 @@ from functools import partial
 
 import jax
 
-from repro import kernels
+from repro.kernels import on_tpu
 from repro.kernels.ssd_scan import kernel as _k
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = None):
     if interpret is None:
-        interpret = kernels.INTERPRET
+        interpret = not on_tpu()
     import jax.numpy as jnp
     b, S, H, P = x.shape
     Q = min(chunk, S)

@@ -24,6 +24,8 @@ def main():
     ap.add_argument("--full", dest="reduced", action="store_false")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config

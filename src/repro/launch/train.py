@@ -1,9 +1,3 @@
-import os
-if "XLA_FLAGS" not in os.environ:
-    # host-device pod simulation (8 fake devices) for --mode pod on CPU;
-    # harmless for --mode sim (single device would also work)
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 DOC = """Federated training driver — the end-to-end e2e deliverable.
 
 Two modes:
@@ -23,6 +17,7 @@ Examples:
 """
 
 import argparse
+import os
 import time
 
 import numpy as np
@@ -134,6 +129,13 @@ def main():
     ap.add_argument("--full", dest="reduced", action="store_false",
                     help="use the full (non-reduced) architecture")
     args = ap.parse_args()
+    if args.mode == "pod":
+        # the (pod, data, model) mesh needs 8 devices: on a CPU host they
+        # are forced host devices; set before JAX starts its backend
+        os.environ.setdefault("XLA_FLAGS",
+                              "--xla_force_host_platform_device_count=8")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.mode == "sim":
         run_sim(args)
     else:

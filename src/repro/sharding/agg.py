@@ -4,13 +4,12 @@ DESIGN.md §Sharded streaming aggregation: the server-side reductions
 (``masked_sum`` / ``masked_sum_corrected`` / ``dequant_reduce`` /
 ``masked_dequant_reduce``) are embarrassingly parallel over the packed
 parameter axis T — every output element depends on one column of the
-(N, T) cohort matrix. This module wraps each op in
-``jax.experimental.custom_partitioning`` (the jetstream ragged-attention
-idiom, SNIPPETS.md) over a 1-D ``("shard",)`` mesh: inputs arrive
-column-sharded ``P(None, "shard")``, per-client scalars replicated
-``P()``, and each device runs the *unsharded* op on its T/n_shards slab —
-no collective at all, the output stays sharded ``P("shard")`` until the
-host gathers it.
+(N, T) cohort matrix. This module maps each op over a 1-D ``("shard",)``
+mesh with ``jax.shard_map``: inputs arrive column-sharded
+``P(None, "shard")``, per-client scalars replicated ``P()``, and each
+device runs the *unsharded* op on its T/n_shards slab — no collective
+at all, the output stays sharded ``P("shard")`` until the host gathers
+it.
 
 Partitioning rules (the module's contract):
 
@@ -37,7 +36,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.custom_partitioning import custom_partitioning
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -68,73 +66,53 @@ def _pad_cols(arr, pad: int):
     return jnp.pad(jnp.asarray(arr), width)
 
 
-def _make_partitioned(local_fn, in_specs):
-    """Wrap ``local_fn`` (which maps whole operands -> (T,) output) so
-    that under jit each device runs it on its T-slab.
-
-    ``in_specs``: one PartitionSpec per operand. The partition rule is
-    static — T-sharded columns in, T-sharded output out, no collectives —
-    so ``infer_sharding_from_operands`` and ``partition`` just restate
-    ``in_specs``; XLA inserts any needed resharding of the inputs.
-    """
-    f = custom_partitioning(local_fn)
-
-    def partition(mesh, arg_shapes, result_shape):
-        del arg_shapes, result_shape
-        arg_sh = tuple(NamedSharding(mesh, s) for s in in_specs)
-        return mesh, local_fn, NamedSharding(mesh, P(AXIS)), arg_sh
-
-    def infer(mesh, arg_shapes, result_shape):
-        del arg_shapes, result_shape
-        return NamedSharding(mesh, P(AXIS))
-
-    f.def_partition(partition=partition,
-                    infer_sharding_from_operands=infer)
-    return f
+def _slab_map(local_fn, mesh: Mesh, in_specs):
+    """Jit ``local_fn`` (whole operands -> (T,) output) so that each
+    device runs it on its own T-slab: T-sharded columns in, T-sharded
+    output out, no collectives. ``check_vma=False``: the Pallas kernels
+    inside carry no varying-axis annotations."""
+    return jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(AXIS), check_vma=False))
 
 
-# --- cached jitted entry points (one compile per op x mesh-size x shape) --
+# --- cached jitted entry points (one compile per op x mesh x shape) ------
 @lru_cache(maxsize=None)
-def _masked_sum_sharded(interpret: Optional[bool]):
-    fn = _make_partitioned(
+def _masked_sum_sharded(mesh: Mesh, interpret: Optional[bool]):
+    return _slab_map(
         lambda x, w: _sec_ops.masked_sum(x, w, interpret=interpret),
-        (P(None, AXIS), P()))
-    return jax.jit(fn)
+        mesh, (P(None, AXIS), P()))
 
 
 @lru_cache(maxsize=None)
-def _masked_sum_corrected_sharded(interpret: Optional[bool]):
-    fn = _make_partitioned(
+def _masked_sum_corrected_sharded(mesh: Mesh, interpret: Optional[bool]):
+    return _slab_map(
         lambda x, c, w: _sec_ops.masked_sum_corrected(
             x, c, w, interpret=interpret),
-        (P(None, AXIS), P(None, AXIS), P()))
-    return jax.jit(fn)
+        mesh, (P(None, AXIS), P(None, AXIS), P()))
 
 
 @lru_cache(maxsize=None)
-def _dequant_reduce_sharded(interpret: Optional[bool]):
-    fn = _make_partitioned(
+def _dequant_reduce_sharded(mesh: Mesh, interpret: Optional[bool]):
+    return _slab_map(
         lambda q, s, w: _comp_ops.dequant_reduce(q, s, w,
                                                  interpret=interpret),
-        (P(None, AXIS), P(None, AXIS), P()))
-    return jax.jit(fn)
+        mesh, (P(None, AXIS), P(None, AXIS), P()))
 
 
 @lru_cache(maxsize=None)
-def _masked_dequant_reduce_sharded(modulus_bits: int, with_corr: bool,
+def _masked_dequant_reduce_sharded(mesh: Mesh, modulus_bits: int,
+                                   with_corr: bool,
                                    interpret: Optional[bool]):
     if with_corr:
-        fn = _make_partitioned(
+        return _slab_map(
             lambda z, c, s: _comp_ops.masked_dequant_reduce(
                 z, s, modulus_bits=modulus_bits, corr=c,
                 interpret=interpret),
-            (P(None, AXIS), P(None, AXIS), P(AXIS)))
-    else:
-        fn = _make_partitioned(
-            lambda z, s: _comp_ops.masked_dequant_reduce(
-                z, s, modulus_bits=modulus_bits, interpret=interpret),
-            (P(None, AXIS), P(AXIS)))
-    return jax.jit(fn)
+            mesh, (P(None, AXIS), P(None, AXIS), P(AXIS)))
+    return _slab_map(
+        lambda z, s: _comp_ops.masked_dequant_reduce(
+            z, s, modulus_bits=modulus_bits, interpret=interpret),
+        mesh, (P(None, AXIS), P(AXIS)))
 
 
 def _placed(mesh, spec, *arrs):
@@ -160,7 +138,7 @@ def sharded_masked_sum(x, weights, *, mesh: Mesh,
     pad = _t_pad(t, mesh.shape[AXIS], LANE)
     (xp,) = _placed(mesh, P(None, AXIS), _pad_cols(x, pad))
     (w,) = _placed(mesh, P(), jnp.asarray(weights, jnp.float32))
-    out = _masked_sum_sharded(interpret)(xp, w)
+    out = _masked_sum_sharded(mesh, interpret)(xp, w)
     return out[:t]
 
 
@@ -173,7 +151,7 @@ def sharded_masked_sum_corrected(x, corr, weights, *, mesh: Mesh,
     xp, cp = _placed(mesh, P(None, AXIS), _pad_cols(x, pad),
                      _pad_cols(jnp.asarray(corr, jnp.float32), pad))
     (w,) = _placed(mesh, P(), jnp.asarray(weights, jnp.float32))
-    out = _masked_sum_corrected_sharded(interpret)(xp, cp, w)
+    out = _masked_sum_corrected_sharded(mesh, interpret)(xp, cp, w)
     return out[:t]
 
 
@@ -196,7 +174,7 @@ def sharded_dequant_reduce(q, scales, weights, *, mesh: Mesh,
     qp, = _placed(mesh, P(None, AXIS), qp)
     sp, = _placed(mesh, P(None, AXIS), sp)
     (w,) = _placed(mesh, P(), jnp.asarray(weights, jnp.float32))
-    out = _dequant_reduce_sharded(interpret)(qp, sp, w)
+    out = _dequant_reduce_sharded(mesh, interpret)(qp, sp, w)
     return out[:t]
 
 
@@ -219,10 +197,10 @@ def sharded_masked_dequant_reduce(z, scales, *, modulus_bits: int,
                             pad // CHUNK))
     if corr is None:
         out = _masked_dequant_reduce_sharded(
-            int(modulus_bits), False, interpret)(zp, sp)
+            mesh, int(modulus_bits), False, interpret)(zp, sp)
     else:
         cp, = _placed(mesh, P(None, AXIS),
                       _pad_cols(jnp.asarray(corr).astype(jnp.uint32), pad))
         out = _masked_dequant_reduce_sharded(
-            int(modulus_bits), True, interpret)(zp, cp, sp)
+            mesh, int(modulus_bits), True, interpret)(zp, cp, sp)
     return out[:t]

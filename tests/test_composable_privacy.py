@@ -271,10 +271,13 @@ def _kernel_case(n, tp, mbits, seed, with_corr):
 @pytest.mark.parametrize("mbits", [16, 32])
 @pytest.mark.parametrize("with_corr", [False, True])
 def test_masked_kernel_matches_ref(mbits, with_corr):
-    for n, tp in ((2, CHUNK), (4, 8 * CHUNK)):
+    # whole-T tiles, then 8-chunk tiles with a partial last tile
+    for n, tp, bt in ((2, CHUNK, 32 * CHUNK), (4, 8 * CHUNK, 32 * CHUNK),
+                      (3, 13 * CHUNK, 8 * CHUNK)):
         z, scales, corr = _kernel_case(n, tp, mbits, n, with_corr)
         got = np.asarray(masked_dequant_reduce_flat(
-            z, scales, modulus_bits=mbits, corr=corr, interpret=True))
+            z, scales, modulus_bits=mbits, corr=corr, bt=bt,
+            interpret=True))
         want = np.asarray(masked_dequant_reduce_ref(
             z, scales, mbits, corr=corr))
         # integer sums are order-independent; the only float op is the

@@ -149,7 +149,8 @@ def test_dequant_reduce_kernel_matches_oracle(n, c):
     scales = rng.uniform(1e-6, 1e-2, size=(n, c)).astype(np.float32)
     w = rng.uniform(0.0, 1.0, size=n).astype(np.float32)
     ref = np.asarray(dequant_reduce_ref(q, scales, w))
-    for bt in (CHUNK, 4096):
+    # one whole-T tile, and 8-chunk tiles with a partial last tile (c=13)
+    for bt in (8 * CHUNK, 32 * CHUNK):
         out = np.asarray(dequant_reduce_flat(q, scales, w, bt=bt,
                                              interpret=True))
         np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)

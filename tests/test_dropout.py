@@ -128,7 +128,7 @@ def test_masked_sum_corrected_op_fallback_matches_kernel():
     c = jax.random.normal(jax.random.PRNGKey(2), (5, 700), jnp.float32)
     w = jnp.full((5,), 0.2)
     np.testing.assert_allclose(
-        np.asarray(masked_sum_corrected(x, c, w, interpret=True)),
+        np.asarray(masked_sum_corrected(x, c, w)),     # oracle off TPU
         np.asarray(masked_sum_corrected_flat(x, c, w, interpret=True)),
         atol=1e-5, rtol=1e-5)
 

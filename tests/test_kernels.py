@@ -132,3 +132,50 @@ def test_combine_pytrees_quantization_error_bounded():
         max_scale = max(float(jnp.max(jnp.abs(l))) / 127.0
                         for t in trees for l in jax.tree.leaves(t))
         assert float(jnp.max(jnp.abs(a - r))) <= max_scale
+
+
+# ---------------------------------------------------------------------------
+# kernel choice by platform
+# ---------------------------------------------------------------------------
+def _combine_calls(n=1):
+    """Each op at cohort size ``n``. The ops pick their branch while they
+    are traced, and a trace is cached per shape, so each test that
+    changes the platform uses an ``n`` of its own."""
+    from repro.kernels.compressed_agg import ops as comp_ops
+    from repro.kernels.secure_agg import ops as sec_ops
+    t, tc = 3 * 1024 + 256 * 3, 4 * 1024
+    x = jnp.ones((n, t), jnp.float32)
+    w = jnp.ones((n,), jnp.float32)
+    q = jnp.ones((n, tc), jnp.int8)
+    s = jnp.ones((n, tc // 1024), jnp.float32)
+    z = jnp.ones((n, tc), jnp.uint32)
+    return {
+        "masked_sum": (sec_ops, lambda: sec_ops.masked_sum(x, w)),
+        "masked_sum_corrected": (
+            sec_ops, lambda: sec_ops.masked_sum_corrected(x, x, w)),
+        "dequant_reduce": (
+            comp_ops, lambda: comp_ops.dequant_reduce(q, s, w)),
+        "masked_dequant_reduce": (
+            comp_ops, lambda: comp_ops.masked_dequant_reduce(
+                z, s[0], modulus_bits=16)),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_combine_calls()))
+def test_combine_op_runs_oracle_off_tpu(name):
+    """Off the TPU, ``interpret=None`` lowers to plain XLA (the oracle):
+    no Pallas call is traced at all."""
+    _, call = _combine_calls(3)[name]
+    assert "pallas_call" not in str(jax.make_jaxpr(call)())
+
+
+@pytest.mark.parametrize("name", sorted(_combine_calls()))
+def test_combine_op_on_tpu_never_returns_oracle(name, monkeypatch):
+    """Where the platform is a TPU, the op traces the compiled Pallas
+    kernel (``interpret=False``), never the oracle and never the
+    interpreter."""
+    ops, call = _combine_calls(5)[name]
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    jaxpr = str(jax.make_jaxpr(call)())
+    assert "pallas_call" in jaxpr
+    assert "interpret=False" in jaxpr

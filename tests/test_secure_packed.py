@@ -105,10 +105,11 @@ def test_masked_sum_kernel_matches_ref(n, t):
 
 
 def test_masked_sum_op_interpret_fallback_matches_kernel():
-    """ops.masked_sum (oracle fallback) == kernel body == ref."""
+    """ops.masked_sum off the TPU (the oracle) == kernel body (interpret
+    mode)."""
     x = jax.random.normal(jax.random.PRNGKey(1), (5, 700), jnp.float32)
     w = jnp.full((5,), 0.2)
-    np.testing.assert_allclose(np.asarray(masked_sum(x, w, interpret=True)),
+    np.testing.assert_allclose(np.asarray(masked_sum(x, w)),
                                np.asarray(masked_sum_flat(x, w,
                                                           interpret=True)),
                                atol=1e-5, rtol=1e-5)
