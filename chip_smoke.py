@@ -149,18 +149,8 @@ def run_job(con, plane: str, *, datasets, schema, reduced: bool,
     run_id = con.start(job, datasets)
     setup_s = time.perf_counter() - t0
 
-    phase_s = defaultdict(float)
-    last = {"phase": "start", "t": time.perf_counter()}
-
-    def on_phase(rid, phase):
-        if rid != run_id:
-            return
-        now = time.perf_counter()
-        phase_s[last["phase"]] += now - last["t"]
-        last.update(phase=phase, t=now)
-
     t1 = time.perf_counter()
-    phase = con.run_to_completion(on_phase=on_phase)
+    phase = con.run_to_completion()
     run_s = time.perf_counter() - t1
     if phase != "done":
         raise RuntimeError(f"{plane}: run ended in phase {phase!r}")
@@ -184,10 +174,14 @@ def run_job(con, plane: str, *, datasets, schema, reduced: bool,
     if pred.shape != (1, 5) or pred.min() < 0 or pred.max() >= schema.vocab:
         raise RuntimeError(f"{plane}: bad prediction {pred.tolist()}")
 
+    # host seconds by span name over the job (nested spans overlap)
+    span_s = defaultdict(float)
+    for sp in con.telemetry.spans(run_id, include_open=False):
+        span_s[sp.name] += sp.t1 - sp.t0
     after = stats.snapshot()
     emit(f"job_{plane}", run_id=run_id, setup_s=setup_s, run_s=run_s,
-         predict_s=predict_s, phase_s=dict(phase_s), losses=losses,
-         prediction=pred[0].tolist(),
+         predict_s=predict_s, span_s=dict(sorted(span_s.items())),
+         losses=losses, prediction=pred[0].tolist(),
          **{k: after[k] - before[k] for k in after})
 
 
@@ -195,10 +189,11 @@ def run_federation(*, reduced: bool, vocab: int, seq_len: int,
                    batch_size: int, rounds: int, local_steps: int,
                    seed: int, stats):
     """Both jobs, one after the other, on one consortium."""
-    from repro.core import Consortium, DataSchema
+    from repro.core import Consortium, DataSchema, Telemetry
     from repro.data import make_silo_datasets
 
-    con = Consortium(ORGS, seed=seed)
+    con = Consortium(ORGS, seed=seed,
+                     telemetry=Telemetry(enabled=True, recorder_cap=1 << 16))
     schema = DataSchema(vocab=vocab, seq_len=seq_len)
     datasets = make_silo_datasets(len(ORGS), vocab=vocab, seq_len=seq_len,
                                   seed=seed + 1)

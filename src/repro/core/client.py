@@ -363,7 +363,7 @@ class FLClientNode:
             msg = self.comm.fetch(f"{base}/global", broadcast=True)
         if msg is None:
             return "waiting_global"
-        base_params = jax.tree.map(jnp.asarray, msg["params"])
+        base_params = self._to_device(msg["params"], rnd)
         try:
             with tel.span("client.train", cat="client",
                           actor=self.client_id, run_id=self.run_id,
@@ -470,7 +470,7 @@ class FLClientNode:
         if msg is None:
             return "waiting_global"
         tel = self.telemetry
-        base_params = jax.tree.map(jnp.asarray, msg["params"])
+        base_params = self._to_device(msg["params"], rnd)
         try:
             with tel.span("client.train", cat="client",
                           actor=self.client_id, run_id=self.run_id,
@@ -558,6 +558,14 @@ class FLClientNode:
                      "epoch": info["epoch"]})
         return "repair_posted"
 
+    def _to_device(self, host_params, rnd: int):
+        """Copy a fetched global to the device. The span times the host's
+        dispatch of the copies only; the device trace shows the transfer."""
+        with self.telemetry.span("client.h2d", cat="client",
+                                 actor=self.client_id, run_id=self.run_id,
+                                 attrs={"round": rnd}):
+            return jax.tree.map(jnp.asarray, host_params)
+
     def _eval_params(self, params, batches: int) -> float:
         losses = []
         for _ in range(batches):
@@ -571,16 +579,20 @@ class FLClientNode:
         if self.eval_done >= rnd and self.eval_hp == hp:
             return "eval_already_done"
         base = f"{self.ns}/round/{hp}/{rnd}"
-        # Model Evaluator: private held-out batches on the latest global
-        # (the new aggregate is distributed next round; this round's global
-        # is the model this client can evaluate without a push)
-        rel = self.comm.fetch(f"{base}/global", broadcast=True)
-        if rel is None:
-            return "waiting_global_eval"
-        params = jax.tree.map(jnp.asarray, rel["params"])
-        eval_loss = self._eval_params(params, self.config.eval_batches)
-        self.comm.post(f"{base}/eval/{self.client_id}",
-                       {"eval_loss": eval_loss})
+        with self.telemetry.span("client.eval", cat="client",
+                                 actor=self.client_id, run_id=self.run_id,
+                                 attrs={"round": rnd}):
+            # Model Evaluator: private held-out batches on the latest
+            # global (the new aggregate is distributed next round; this
+            # round's global is the model this client can evaluate
+            # without a push)
+            rel = self.comm.fetch(f"{base}/global", broadcast=True)
+            if rel is None:
+                return "waiting_global_eval"
+            params = self._to_device(rel["params"], rnd)
+            eval_loss = self._eval_params(params, self.config.eval_batches)
+            self.comm.post(f"{base}/eval/{self.client_id}",
+                           {"eval_loss": eval_loss})
         self.eval_done, self.eval_hp = rnd, hp
         return "eval_posted"
 

@@ -34,6 +34,35 @@ def _run_of(path: str) -> Optional[str]:
             return path[5:end]
     return None
 
+
+def _seal(tel: Telemetry, key: bytes, body, actor: str, path: str) -> bytes:
+    """Pack and encrypt one message, each stage in its own span."""
+    run_id = _run_of(path)
+    with tel.span("wire.pack", cat="wire", actor=actor,
+                  run_id=run_id) as sp:
+        plain = serialization.pack(body)
+        sp.set(bytes=len(plain))
+    with tel.span("wire.encrypt", cat="wire", actor=actor,
+                  run_id=run_id) as sp:
+        blob = crypto.encrypt(key, plain)
+        sp.set(bytes=len(blob), compressed=crypto.compressed(blob))
+    return blob
+
+
+def _unseal(tel: Telemetry, key: bytes, blob: bytes, actor: str,
+            path: str):
+    """Decrypt and unpack one message, each stage in its own span."""
+    run_id = _run_of(path)
+    with tel.span("wire.decrypt", cat="wire", actor=actor,
+                  run_id=run_id) as sp:
+        plain = crypto.decrypt(key, blob)
+        sp.set(bytes=len(blob))
+    with tel.span("wire.unpack", cat="wire", actor=actor,
+                  run_id=run_id) as sp:
+        sp.set(bytes=len(plain))
+        return serialization.unpack(plain)
+
+
 __all__ = ["Resource", "MessageBoard", "ServerCommunicator",
            "ClientCommunicator"]
 
@@ -307,15 +336,15 @@ class ServerCommunicator:
                else self.broadcast_key())
         body = {"server_id": self.server_id, "cert": self.cert,
                 "payload": payload}
-        self.board.put_server(path, crypto.encrypt(key,
-                                                   serialization.pack(body)))
+        self.board.put_server(path, _seal(self.board.telemetry, key, body,
+                                          "server", path))
 
     def collect(self, path: str, client_id: str):
         blob = self.board.get(path)
         if blob is None:
             return None
-        return serialization.unpack(
-            crypto.decrypt(self.channel_key(client_id), blob))
+        return _unseal(self.board.telemetry, self.channel_key(client_id),
+                       blob, "server", path)
 
     def collect_heartbeats(self, run_id: str, cohort) -> Dict[str, int]:
         """Liveness view: client_id -> overwrite version of the latest
@@ -357,7 +386,7 @@ class ClientCommunicator:
         blob = self.board.get(path, reader=self.client_id)
         if blob is None:
             return None
-        return self._open(blob, broadcast=broadcast)
+        return self._open(path, blob, broadcast=broadcast)
 
     def fetch_cached(self, path: str, *, broadcast: bool = False):
         """Conditional fetch: re-download only when the resource's
@@ -377,15 +406,15 @@ class ClientCommunicator:
                 self._fetch_cache.pop(path, None)
                 return self.fetch_cached(path, broadcast=broadcast)
             return cached                  # 304: unchanged since last look
-        payload = self._open(blob, broadcast=broadcast)
+        payload = self._open(path, blob, broadcast=broadcast)
         self._fetch_cache[path] = (version, payload)
         while len(self._fetch_cache) > self.FETCH_CACHE_CAP:
             self._fetch_cache.pop(next(iter(self._fetch_cache)))
         return payload
 
-    def _open(self, blob: bytes, *, broadcast: bool):
+    def _open(self, path: str, blob: bytes, *, broadcast: bool):
         key = self.broadcast_key if broadcast else self.channel_key
-        body = serialization.unpack(crypto.decrypt(key, blob))
+        body = _unseal(self.board.telemetry, key, blob, self.client_id, path)
         # server authentication (§VII): verify certificate before trusting
         if self.ca_key is not None:
             if not crypto.verify_certificate(body["server_id"], body["cert"],
@@ -404,7 +433,8 @@ class ClientCommunicator:
             time.sleep(interval)
 
     def post(self, path: str, payload):
-        blob = crypto.encrypt(self.channel_key, serialization.pack(payload))
+        blob = _seal(self.board.telemetry, self.channel_key, payload,
+                     self.client_id, path)
         self.board.put_client(self.client_id, self.token, path, blob)
 
     def heartbeat(self, run_id: str, n: int):

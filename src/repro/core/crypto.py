@@ -93,6 +93,11 @@ def encrypt(key: bytes, plaintext: bytes, *, compress="auto") -> bytes:
     return tag + body
 
 
+def compressed(blob: bytes) -> bool:
+    """Whether ``encrypt`` zlib-compressed this blob's plaintext."""
+    return blob[32:33] == b"\x01"
+
+
 def decrypt(key: bytes, blob: bytes) -> bytes:
     tag, body = blob[:32], blob[32:]
     want = hmac.new(derive_key(key, "mac"), body, hashlib.sha256).digest()

@@ -629,9 +629,10 @@ class AsyncServePhase(Phase):
             meta = metas[path]
             if meta is None or meta["version"] <= st["seen"].get(cid, 0):
                 continue
-            msg = server.comm.collect(path, cid)
+            with server._ingest_span(cid):
+                msg = server.comm.collect(path, cid)
+                self._fold(server, cid, msg)
             st["seen"][cid] = meta["version"]
-            self._fold(server, cid, msg)
             if st["folds"] >= r.job.async_buffer_size:
                 done = self._commit(server)
                 if done:

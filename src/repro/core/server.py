@@ -260,14 +260,17 @@ class FLServer:
         through here, and an inner-tier executor replaces it wholesale
         (the silo hands base params to its devices directly — no board)."""
         r = self.run
-        params = self.store.get(r.global_digest)
-        self.comm.publish(
-            f"{r.ns}/round/{r.hp_index}/{r.round}/global",
-            {"digest": r.global_digest,
-             "params": jax.tree.map(np.asarray, params),
-             "round": r.round, "lr": self._job_lr(r.job),
-             "cohort": list(cohort),
-             "weight_denom": r.job.local_steps * r.job.batch_size})
+        with self.telemetry.span("server.publish_global", cat="server",
+                                 actor="server", run_id=r.run_id,
+                                 attrs={"round": r.round}):
+            params = self.store.get(r.global_digest)
+            self.comm.publish(
+                f"{r.ns}/round/{r.hp_index}/{r.round}/global",
+                {"digest": r.global_digest,
+                 "params": jax.tree.map(np.asarray, params),
+                 "round": r.round, "lr": self._job_lr(r.job),
+                 "cohort": list(cohort),
+                 "weight_denom": r.job.local_steps * r.job.batch_size})
 
     def _publish_status(self):
         r = self.run
@@ -421,7 +424,9 @@ class FLServer:
             # safe — nothing folded here can leave the cohort this tick
             for cid in list(r.cohort):
                 if cid not in seen and metas[path_for(cid)] is not None:
-                    on_arrival(cid, self.comm.collect(path_for(cid), cid))
+                    with self._ingest_span(cid):
+                        on_arrival(cid, self.comm.collect(path_for(cid),
+                                                          cid))
                     seen.add(cid)
         if missing:
             self._enforce_deadline(missing, waiting_for)
@@ -437,6 +442,14 @@ class FLServer:
                 self.comm, {cid: path_for(cid) for cid in r.cohort})
         return {cid: self.comm.collect(path_for(cid), cid)
                 for cid in r.cohort}
+
+    def _ingest_span(self, cid: str):
+        """Span over taking in one client's update: collect (get,
+        decrypt, unpack) and the fold into the round's container."""
+        return self.telemetry.span(
+            "server.ingest", cat="server", actor="server",
+            run_id=self.run.run_id,
+            attrs={"round": self.run.round, "client": cid})
 
     def _fold_update(self, container, cid: str, payload, weight: float):
         """Route one client's round payload into the round's aggregation
@@ -507,6 +520,13 @@ class FLServer:
     # --- Model Aggregator ---------------------------------------------
     def _aggregate_and_advance(self, updates, sizes, losses,
                                corrections=None):
+        r = self.run
+        with self.telemetry.span("server.aggregate", cat="server",
+                                 actor="server", run_id=r.run_id,
+                                 attrs={"round": r.round}):
+            self._aggregate_inner(updates, sizes, losses, corrections)
+
+    def _aggregate_inner(self, updates, sizes, losses, corrections):
         from repro.core import streaming
         r = self.run
         job = r.job

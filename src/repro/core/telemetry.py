@@ -12,12 +12,16 @@ and on which silo's link?"*. This module is that instrument panel
 * **Span tracer** — nested spans opened by the scheduler (pass / admit /
   preempt), the server's protocol phases (one span per phase *visit*,
   opened on enter and closed on the transition out, however many ticks
-  that takes), client agents (fetch / train / compress / post) and the
-  board's per-RPC transport calls. Every span is stamped with BOTH the
-  wall clock and — when a :class:`~repro.core.transport.WanModel` is
+  that takes), the server's stages (ingest / publish_global /
+  aggregate), client agents (fetch / train / compress / post / eval /
+  h2d), each message's wire stages (pack / encrypt / decrypt / unpack)
+  and the board's per-RPC transport calls. Every span is stamped with
+  BOTH the wall clock and — when a :class:`~repro.core.transport.WanModel` is
   attached — the acting actor's *simulated* clock, so a trace of a
   simulated-WAN bench explains where the simulated seconds went, not
-  just the host seconds.
+  just the host seconds. Lexical spans are mirrored into the profiler's
+  trace as ``jax.profiler.TraceAnnotation``s, so under a profiler
+  session they share the device trace's clock.
 * **Metrics registry** — one ``Counter`` / ``Gauge`` / ``Histogram`` API
   with labeled series (per-run, per-silo, per-scheme). The components'
   legacy ``stats`` dicts are now *views* assembled from registry
@@ -47,6 +51,8 @@ import json
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "Span", "Telemetry"]
@@ -232,10 +238,12 @@ class Span:
     are the acting actor's WanModel simulated clock when one is attached
     (``None`` otherwise). ``t1 is None`` marks a still-open span (a
     phase the run is currently in) — export treats it as running up to
-    the export instant."""
+    the export instant. A lexical span (``Telemetry.span``) also holds
+    the profiler annotation that mirrors it (``_annotation``)."""
 
     __slots__ = ("span_id", "parent_id", "name", "cat", "actor", "run_id",
-                 "t0", "t1", "sim0", "sim1", "attrs", "_telemetry")
+                 "t0", "t1", "sim0", "sim1", "attrs", "_telemetry",
+                 "_annotation")
 
     def __init__(self, span_id, parent_id, name, cat, actor, run_id,
                  t0, sim0, attrs):
@@ -250,6 +258,7 @@ class Span:
         self.sim0 = sim0
         self.sim1 = None
         self.attrs = attrs
+        self._annotation = None
 
     def set(self, **attrs):
         """Attach attributes mid-span (a train span learns its loss)."""
@@ -354,11 +363,19 @@ class Telemetry:
              run_id: Optional[str] = None, attrs: Optional[dict] = None):
         """Open a span as a context manager. Disabled: returns the shared
         no-op immediately — build expensive ``attrs`` only behind an
-        ``if telemetry.enabled`` guard."""
+        ``if telemetry.enabled`` guard.
+
+        Enabled, the span is mirrored into the profiler's own trace: a
+        ``jax.profiler.TraceAnnotation`` of the same name opens with it
+        and closes with it, so under a profiler session the program's
+        spans sit on the trace's clock beside the device ops (outside
+        one the annotation records nothing)."""
         if not self.enabled:
             return _NULL_SPAN
         sp = self._open_span(name, cat, actor, run_id, attrs)
         sp._telemetry = self
+        sp._annotation = TraceAnnotation(name)
+        sp._annotation.__enter__()
         self._stack.append(sp)
         return sp
 
@@ -395,6 +412,9 @@ class Telemetry:
         if self._stack and self._stack[-1] is sp:
             self._stack.pop()
         sp.t1 = self.clock()
+        if sp._annotation is not None:
+            sp._annotation.__exit__(None, None, None)
+            sp._annotation = None
         sp.sim1 = self._sim_now(sp.actor)
         if error:
             sp.set(error=True)

@@ -8,7 +8,10 @@ compressed+secure round traced end to end over a simulated WAN exports
 valid Chrome-trace JSON with scheduler, phase, per-silo client and
 transport RPC spans on both clock lanes, digest on the provenance chain.
 """
+import glob
 import json
+import os
+import time
 
 import pytest
 
@@ -146,7 +149,30 @@ def test_incident_dump_is_bounded():
     assert tel.incidents[-1]["spans"][0]["name"] == "work"
 
 
-def test_disabled_telemetry_records_nothing():
+class _Recorded:
+    """Stand-in for ``TraceAnnotation`` that records what it is given."""
+
+    def __init__(self, log, name):
+        self.log, self.name = log, name
+        log.append(("made", name))
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_disabled_telemetry_records_nothing(monkeypatch):
+    from repro.core import telemetry
+    made = []
+    monkeypatch.setattr(telemetry, "TraceAnnotation",
+                        lambda name: _Recorded(made, name))
+    real_span = telemetry.Span
+    monkeypatch.setattr(telemetry, "Span",
+                        lambda *a, **k: made.append(("span", a[2])))
     tel = Telemetry()                            # default: off
     s1 = tel.span("a", attrs={"k": 1})
     s2 = tel.span("b")
@@ -155,6 +181,11 @@ def test_disabled_telemetry_records_nothing():
         s1.set(x=1)
     assert tel.open_span("phase:x") == 0
     assert tel.spans("r1") == []
+    assert made == []                            # no Span, no annotation
+    monkeypatch.setattr(telemetry, "Span", real_span)
+    with Telemetry(enabled=True).span("on"):     # the probes are live
+        pass
+    assert made == [("made", "on"), ("enter", "on"), ("exit", "on")]
     with tel.kernel_span("masked_sum"):
         pass                                     # histogram always feeds
     assert tel.metrics.snapshot()["kernel.seconds"][
@@ -305,3 +336,120 @@ def test_metadata_clock_injection():
     ts = [r["ts"] for r in md.query(kind="provenance")]
     assert ts == [0.0, 1.0]                      # deterministic under test
     assert md.verify_chain()
+
+
+# ---------------------------------------------------------------------------
+# stage spans of a secure round, and their mirror in the profiler's trace
+# ---------------------------------------------------------------------------
+STAGE_SPANS = ("wire.pack", "wire.encrypt", "wire.decrypt", "wire.unpack",
+               "server.ingest", "server.publish_global", "server.aggregate",
+               "client.eval", "client.h2d")
+
+
+def _ancestors(span, by_id):
+    names, pid = [], span.parent_id
+    while pid in by_id:
+        names.append(by_id[pid].name)
+        pid = by_id[pid].parent_id
+    return names
+
+
+def _sibling(span, spans, names, after: bool):
+    """The nearest span named in ``names`` with the same parent and actor,
+    opened after ``span`` (or before it)."""
+    near = [s for s in spans if s.name in names
+            and s.parent_id == span.parent_id and s.actor == span.actor
+            and (s.span_id > span.span_id if after
+                 else s.span_id < span.span_id)]
+    return (min if after else max)(near, key=lambda s: s.span_id)
+
+
+@pytest.mark.parametrize("plane", [
+    {"secure_aggregation": True},
+    {"secure_aggregation": True, "compression": "int8"},
+], ids=["secure_f32", "secure_int8"])
+def test_stage_spans_cover_a_secure_round(plane):
+    tel = Telemetry(enabled=True)
+    sched, cids = make_fleet(n_silos=2, capacity=1, telemetry=tel)
+    run_id = submit_job(sched, cids, **plane)
+    sched.run(max_passes=500)
+    assert sched.entries[run_id].state == "done"
+
+    spans = tel.spans(run_id)
+    by_id = {s.span_id: s for s in spans}
+    assert {s.run_id for s in spans} == {run_id}
+    assert set(STAGE_SPANS) <= {s.name for s in spans}
+    wire = [s for s in spans if s.name.startswith("wire.")]
+    assert {s.actor for s in wire} == {"server", *cids}
+
+    def nested(name, outer):
+        return any(outer in _ancestors(s, by_id)
+                   for s in spans if s.name == name)
+    for outer in ("server.ingest", "client.fetch", "client.eval"):
+        assert nested("wire.decrypt", outer), outer
+    for outer in ("server.publish_global", "client.post"):
+        assert nested("wire.encrypt", outer), outer
+    assert nested("client.h2d", "client.eval")
+
+    posts = [s for s in spans if s.name == "client.post"]
+    ingests = [s for s in spans if s.name == "server.ingest"]
+    assert len(posts) == len(cids)
+    assert sorted(s.attrs["client"] for s in ingests) == sorted(
+        s.actor for s in posts)
+    assert {s.attrs["round"] for s in ingests} == {0}
+
+    # the cipher's byte counts are the board's, message by message
+    for enc in (s for s in spans if s.name == "wire.encrypt"):
+        put = _sibling(enc, spans, ("board.put",), after=True)
+        assert enc.attrs["bytes"] == put.attrs["bytes"]
+    for dec in (s for s in spans if s.name == "wire.decrypt"):
+        get = _sibling(dec, spans, ("board.get", "board.get_if_newer"),
+                       after=False)
+        assert dec.attrs["bytes"] == get.attrs["bytes"]
+    update = [s for s in wire if s.name == "wire.encrypt"
+              and "client.post" in _ancestors(s, by_id)]
+    assert all(s.attrs["compressed"] is False for s in update)
+
+
+def test_spans_mirror_into_the_profiler_trace(tmp_path):
+    """Lexical spans appear on the profiler's host plane with their own
+    durations and one constant offset between the two clocks: what the
+    benchmark's one-marker mapping of host spans onto the trace assumes.
+    Non-lexical spans (``open_span``) are not mirrored."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tel = Telemetry(enabled=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("t.outer", run_id="r1"):
+            with tel.span("t.mid", run_id="r1"):
+                time.sleep(0.004)
+                with tel.span("t.inner", run_id="r1"):
+                    time.sleep(0.002)
+            sid = tel.open_span("phase:t", run_id="r1")
+            time.sleep(0.001)
+            tel.close_span(sid)
+            with tel.span("t.after", run_id="r1"):
+                time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, []).append(ev)
+    spans = {s.name: s for s in tel.spans("r1")}
+    lexical = ["t.outer", "t.mid", "t.inner", "t.after"]
+    assert "phase:t" not in host
+    offsets = []
+    for name in lexical:
+        (ev,) = host[name]
+        sp = spans[name]
+        assert ev.duration_ns * 1e-9 == pytest.approx(sp.t1 - sp.t0,
+                                                      abs=1e-3)
+        offsets.append(ev.start_ns * 1e-9 - sp.t0)
+    assert max(offsets) - min(offsets) < 1e-3
