@@ -115,21 +115,36 @@ def check_board_child():
 
 def time_host_crypto(t: int, seed: int):
     """One update-sized post through the Communicator's cipher: this is
-    host time that every upload and download in the rounds pays."""
+    host time that every upload and download in the rounds pays. Beside
+    the whole encrypt and decrypt, the cipher's pieces on the same
+    buffer: the SHAKE-256 keystream, the XOR and the HMAC-SHA256."""
+    import hashlib
+    import hmac
+
     import numpy as np
 
     from repro.core import crypto
     buf = np.random.default_rng(seed).standard_normal(t, np.float32)
+    plain_in = buf.tobytes()
     key = crypto.derive_key(b"chip-smoke", "channel")
     t0 = time.perf_counter()
-    blob = crypto.encrypt(key, buf.tobytes())
+    blob = crypto.encrypt(key, plain_in)
     t1 = time.perf_counter()
     plain = crypto.decrypt(key, blob)
     t2 = time.perf_counter()
-    if plain != buf.tobytes():
+    if bytes(plain) != plain_in:
         raise RuntimeError("crypto round trip changed the payload")
+    t3 = time.perf_counter()
+    stream = hashlib.shake_256(key + bytes(16)).digest(len(plain_in))
+    t4 = time.perf_counter()
+    np.bitwise_xor(np.frombuffer(plain_in, np.uint8),
+                   np.frombuffer(stream, np.uint8))
+    t5 = time.perf_counter()
+    hmac.new(key, plain_in, hashlib.sha256).digest()
+    t6 = time.perf_counter()
     emit("host_crypto", bytes=len(plain), encrypt_s=t1 - t0,
-         decrypt_s=t2 - t1)
+         decrypt_s=t2 - t1, keystream_s=t4 - t3, xor_s=t5 - t4,
+         hmac_s=t6 - t5)
 
 
 # --------------------------------------------------------------------------

@@ -49,14 +49,21 @@ def _seal(tel: Telemetry, key: bytes, body, actor: str, path: str) -> bytes:
     return blob
 
 
+# blobs whose tag ``crypto.decrypt`` checked beside the keystream
+COUNTER_MAC_OVERLAPPED = "wire.mac_overlapped"
+
+
 def _unseal(tel: Telemetry, key: bytes, blob: bytes, actor: str,
             path: str):
     """Decrypt and unpack one message, each stage in its own span."""
     run_id = _run_of(path)
+    overlapped = crypto.overlapped(blob)
     with tel.span("wire.decrypt", cat="wire", actor=actor,
                   run_id=run_id) as sp:
         plain = crypto.decrypt(key, blob)
-        sp.set(bytes=len(blob))
+        sp.set(bytes=len(blob), overlapped=overlapped)
+    if overlapped:
+        tel.metrics.counter(COUNTER_MAC_OVERLAPPED).inc()
     with tel.span("wire.unpack", cat="wire", actor=actor,
                   run_id=run_id) as sp:
         sp.set(bytes=len(plain))
@@ -133,6 +140,7 @@ class MessageBoard:
         self._c_stat_calls = reg.counter("board.stat_calls")
         self._c_stat_probes = reg.counter("board.stat_probes")
         self._c_probes_saved = reg.counter("board.probes_saved")
+        reg.counter(COUNTER_MAC_OVERLAPPED)   # reads 0 until a large open
 
     @property
     def stats(self) -> dict:
