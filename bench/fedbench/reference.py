@@ -24,6 +24,17 @@ operands and e5m2 gradients),
 quantizes the delta on a 4-bit grid (round to nearest), ``half_batch``
 trains on the first half of every batch, ``altered_update`` doubles one
 silo's posted delta.
+
+Device memory. With T the model's parameters, ``follow`` holds at most
+5T float32 on the device, plus one step's activations: the FedAvg
+accumulator, and the silo's params, AdamW moments and gradients inside
+its step. The round's global and the int8 residuals wait on the host as
+numpy; each silo trains from a fresh device copy of the global, which
+its first step consumes (the step donates params and moments), and the
+int8 encode takes another copy once the moments are gone. A copy between
+host and device is exact, and the accumulator's sums are the same ops on
+the same device, so the committed globals are those a reference keeping
+everything on the device commits, bit for bit.
 """
 from __future__ import annotations
 
@@ -111,7 +122,7 @@ class Reference:
         self.fed = cfg["federation"]
         self.variant = variant
         self.mod = load_model_module(cfg)
-        self._step = jax.jit(self._train_step)
+        self._step = jax.jit(self._train_step, donate_argnums=(0, 1))
         self._norms = jax.jit(self._leaf_grad_norms)
         self._init = jax.jit(partial(self.mod.init, self.model))
 
@@ -137,15 +148,16 @@ class Reference:
         return jax.tree.map(jnp.linalg.norm, grads)
 
     def train_silo(self, base, batches):
-        """Local AdamW steps from ``base``; returns ``(params, last loss,
-        per-leaf gradient norms at the first step)``."""
+        """Local AdamW steps from ``base``, a device copy that the first
+        step consumes; returns ``(params, last loss, per-leaf gradient
+        norms at the first step)``."""
+        first = self._norms(base, {"tokens": jnp.asarray(batches[0])})
         params = base
         state = {"m": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                    base),
                  "v": jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                    base),
                  "count": jnp.zeros((), jnp.int32)}
-        first = self._norms(base, {"tokens": jnp.asarray(batches[0])})
         loss = None
         for tokens in batches:
             params, state, metrics = self._step(
@@ -160,63 +172,78 @@ class Reference:
         dt = self.fed["update_dtype"]
         return int(dt[3:]) if dt.startswith("int") else 0
 
+    def silo_turn(self, i, glob, batches, residual):
+        """Silo ``i``'s local steps from the host global ``glob`` and the
+        encode of its update. Returns ``(post, last loss, first gradient
+        norms, residual)``: the post on the device, the int8 plane's
+        error-feedback residual on the host (``None`` on float32)."""
+        params, loss, gnorms = self.train_silo(jax.device_put(glob), batches)
+        bits = self._int_bits()
+        if self.variant == "altered_update" and i == 0:
+            base = jax.device_put(glob)
+            params = jax.tree.map(lambda p, g: g + 2.0 * (p - g),
+                                  params, base)
+            del base
+        if not bits:
+            if self.variant == "wire_bf16":
+                params = jax.tree.map(
+                    lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
+                    params)
+            return params, loss, gnorms, None
+        delta = jax.tree.map(jnp.subtract, params, jax.device_put(glob))
+        del params
+        target = delta if residual is None else jax.tree.map(
+            jnp.add, delta, jax.device_put(residual))
+        del delta
+        qmax = (1 << (bits - 1)) - 1
+        grid = self.fed["quant_range"] / qmax
+        if self.variant == "wire_int4":
+            sent = jax.tree.map(lambda t: jnp.clip(
+                jnp.round(t / grid), -qmax, qmax) * grid, target)
+        else:
+            sent = jax.tree.map(lambda t: jnp.clip(
+                t, -qmax * grid, qmax * grid), target)
+        return sent, loss, gnorms, host(jax.tree.map(jnp.subtract, target,
+                                                     sent))
+
     def run_round(self, glob, silo_batches, residuals=None):
         """One synchronous round over the cohort.
 
-        ``silo_batches``: per silo (cohort order), the token batches of
-        its local steps. Returns ``(new_global, losses, first_grad_norms,
-        residuals)``; every silo carries the same FedAvg weight (equal
-        example budgets), so the weighted mean is the plain mean.
+        ``glob``: the round's global, on the host. ``silo_batches``: per
+        silo (cohort order), the token batches of its local steps.
+        Returns ``(new_global, losses, first_grad_norms, residuals)``,
+        the global and the residuals on the host; every silo carries the
+        same FedAvg weight (equal example budgets), so the weighted mean
+        is the plain mean.
         """
         n = len(silo_batches)
         residuals = residuals or [None] * n
-        bits = self._int_bits()
         acc = jax.tree.map(jnp.zeros_like, glob)
         losses, gnorms, new_res = [], [], []
         for i, batches in enumerate(silo_batches):
-            params, loss, gn = self.train_silo(glob, batches)
+            post, loss, gn, res = self.silo_turn(i, glob, batches,
+                                                 residuals[i])
+            acc = jax.tree.map(jnp.add, acc, post)
+            del post
             losses.append(loss)
             gnorms.append(gn)
-            if self.variant == "altered_update" and i == 0:
-                params = jax.tree.map(lambda p, g: g + 2.0 * (p - g),
-                                      params, glob)
-            delta = jax.tree.map(jnp.subtract, params, glob)
-            if bits:
-                target = delta if residuals[i] is None else jax.tree.map(
-                    jnp.add, delta, residuals[i])
-                qmax = (1 << (bits - 1)) - 1
-                grid = self.fed["quant_range"] / qmax
-                if self.variant == "wire_int4":
-                    sent = jax.tree.map(lambda t: jnp.clip(
-                        jnp.round(t / grid), -qmax, qmax) * grid, target)
-                else:
-                    sent = jax.tree.map(lambda t: jnp.clip(
-                        t, -qmax * grid, qmax * grid), target)
-                new_res.append(jax.tree.map(jnp.subtract, target, sent))
-                acc = jax.tree.map(jnp.add, acc, sent)
-            else:
-                post = params
-                if self.variant == "wire_bf16":
-                    post = jax.tree.map(
-                        lambda a: a.astype(jnp.bfloat16).astype(jnp.float32),
-                        post)
-                acc = jax.tree.map(jnp.add, acc, post)
-                new_res.append(None)
+            new_res.append(res)
         mean = jax.tree.map(lambda a: a / np.float32(n), acc)
-        new = (jax.tree.map(jnp.add, glob, mean) if bits else mean)
-        return new, losses, gnorms, new_res
+        del acc
+        if self._int_bits():
+            mean = jax.tree.map(jnp.add, jax.device_put(glob), mean)
+        return host(mean), losses, gnorms, new_res
 
     def follow(self, seed: int, rounds_batches) -> dict:
         """The rounds from the seed's weights; ``rounds_batches``: per
         round, per silo, the token batches of its local steps."""
-        glob = self.init_params(weights_key(seed))
-        out = {"init": host(glob), "globals": [], "losses": [],
-               "grad_norms": []}
+        glob = host(self.init_params(weights_key(seed)))
+        out = {"init": glob, "globals": [], "losses": [], "grad_norms": []}
         residuals = None
         for batches in rounds_batches:
             glob, losses, gnorms, residuals = self.run_round(
                 glob, batches, residuals)
-            out["globals"].append(host(glob))
+            out["globals"].append(glob)
             out["losses"].append(losses)
             out["grad_norms"].extend(gnorms)
         return out
